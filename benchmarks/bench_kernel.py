@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.core.butterfly import count_butterflies_dense, count_butterflies_tiled
 from repro.core.executor import WindowExecutor
+from repro.launch.compile_cache import use_compile_cache
 from repro.streams import bipartite_pa_stream
 
 __all__ = ["run"]
@@ -65,6 +66,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="small shapes + fewer tiers (CI smoke check)")
     args = ap.parse_args()
+    use_compile_cache()
     print("name,us_per_call,derived")
     for name, us, derived in run(quick=args.quick):
         print(f"{name},{us:.1f},{derived}")

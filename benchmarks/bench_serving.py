@@ -34,6 +34,7 @@ import json
 import tempfile
 import time
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.streams.config import EngineConfig
 from repro.streams.generators import bipartite_pa_stream
 from repro.streams.server import StreamServer
@@ -222,6 +223,8 @@ def run_chaos(*, quick: bool = False, tier: str = "numpy") -> None:
 
     with tempfile.TemporaryDirectory(prefix="sgrapp-chaos-") as d:
         final = asyncio.run(scenario(d))
+    # the first JAX computation of this process comes only now, after both
+    # servers exited: on a chip host the children must own the chip
     eng = StreamingSGrapp(nt_w, alpha0, config=EngineConfig(tier=tier))
     eng.push(stream.tau, stream.edge_i, stream.edge_j)
     ref = eng.finalize()
@@ -247,6 +250,7 @@ def main() -> None:
     ap.add_argument("--chaos", action="store_true",
                     help="SIGKILL/recover smoke instead of benchmark rows")
     args = ap.parse_args()
+    use_compile_cache()
     if args.chaos:
         run_chaos(quick=args.quick,
                   tier="numpy" if args.tier == "dense" else args.tier)

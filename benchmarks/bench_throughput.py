@@ -66,6 +66,7 @@ from repro.core.executor import WindowExecutor
 from repro.core.fleet import fleet_run, fleet_run_chunked, reservoir_run
 from repro.core.sgrapp import mape, run_sgrapp
 from repro.core.windows import window_bounds
+from repro.launch.compile_cache import use_compile_cache
 from repro.streams import (
     MultiStreamSGrapp,
     StreamingSGrapp,
@@ -666,6 +667,7 @@ def main() -> None:
     ap.add_argument("--no-json", action="store_true",
                     help="skip writing BENCH_*.json artifacts")
     args = ap.parse_args()
+    use_compile_cache()
     sfx = args.artifact_suffix
     print("name,us_per_call,derived")
     if not (args.streaming_only or args.multistream_only

@@ -22,8 +22,11 @@ def main() -> None:
         bench_kernel,
         bench_throughput,
     )
+    from repro.launch.compile_cache import use_compile_cache
+
     from .artifacts import write_bench_json
 
+    use_compile_cache()
     suites = [
         ("densification", bench_densification.run),
         ("hubs", bench_hubs.run),

@@ -528,7 +528,10 @@ def count_butterflies_tiled(adj: jax.Array, tile: int = 512) -> jax.Array:
         c, _ = jax.lax.scan(inner, carry, jnp.arange(n_blocks))
         return c, None
 
-    total, _ = jax.lax.scan(outer, jnp.zeros((), acc), jnp.arange(n_blocks))
+    # the zero carry comes from the input so that, under shard_map, it has
+    # the same varying-manual-axes type as the per-pair sums added to it
+    zero = jnp.sum(a[:0])
+    total, _ = jax.lax.scan(outer, zero, jnp.arange(n_blocks))
     return total
 
 
@@ -568,7 +571,10 @@ def count_butterflies_tiled_multiset(adj: jax.Array,
         c, _ = jax.lax.scan(inner, carry, jnp.arange(n_blocks))
         return c, None
 
-    total, _ = jax.lax.scan(outer, jnp.zeros((), acc), jnp.arange(n_blocks))
+    # the zero carry comes from the input so that, under shard_map, it has
+    # the same varying-manual-axes type as the per-pair sums added to it
+    zero = jnp.sum(a[:0])
+    total, _ = jax.lax.scan(outer, zero, jnp.arange(n_blocks))
     return total
 
 
@@ -592,7 +598,7 @@ def count_butterflies_sparse(
 
     1. sort edges by ``(j, i)`` — invalid lanes carry sentinel ids ``(n_j,
        n_i)`` so they group last — and invalidate exact duplicates;
-    2. a second stable sort compacts the surviving edges back into
+    2. a second sort compacts the surviving edges back into
        contiguous j-groups (dup lanes rejoin the sentinel group);
     3. every edge of in-group rank ``r`` owes ``r`` wedges, one per earlier
        group member; an inclusive rank cumsum + ``searchsorted`` scatters
@@ -602,13 +608,18 @@ def count_butterflies_sparse(
     4. sort wedges by ``(i1, i2)`` and aggregate each run of equal keys:
        summing every live wedge's within-run rank is exactly
        ``sum_runs C(mult, 2)`` — the segment-sum of wedge multiplicities
-       with the C(w, 2) epilogue algebraically folded in, computed with a
-       cummax instead of a segment scatter (scatters are the slowest
-       primitive on every XLA backend).
+       with the C(w, 2) epilogue algebraically folded in; each run's start
+       is a ``searchsorted`` of the sorted keys into themselves instead of
+       a segment scatter (scatters are the slowest primitive on every XLA
+       backend).
 
     Both sort phases pack their id pair into a single int32 key — XLA's
     variadic multi-key sort lowers to a generic comparator loop that is
-    several times slower than the single-key path.
+    several times slower than the single-key path.  The sorts are unstable:
+    equal int keys are indistinguishable, so the result is the same, and the
+    TPU compiler builds an unstable sort several times faster.  For the same
+    reason the wedge-run starts avoid ``cummax``: over a wedge lane of 2**20
+    slots it alone takes the TPU compiler about 35 s.
 
     ``wedge_cap`` must bound the window's wedge count (the executor computes
     it host-side per bucket and rounds it up the capacity ladder); dead
@@ -632,10 +643,11 @@ def count_butterflies_sparse(
     jj = jnp.where(valid, edge_j, n_j).astype(jnp.int32)
     # sort edges by packed (j, i); invalid lanes carry (n_j, n_i) => last
     span_i = jnp.int32(n_i + 2)
-    ekey = jnp.sort(jj * span_i + ii)
+    ekey = jax.lax.sort(jj * span_i + ii, is_stable=False)
     dup = (~first) & (ekey == jnp.roll(ekey, 1))
     sent = jnp.int32(n_j) * span_i              # every live key sorts below
-    ekey = jnp.sort(jnp.where(dup, sent + ii, ekey))  # compact dups out
+    # compact dups out
+    ekey = jax.lax.sort(jnp.where(dup, sent + ii, ekey), is_stable=False)
     jj = ekey // span_i
     ii = ekey - jj * span_i
     live = jj < n_j
@@ -657,10 +669,10 @@ def count_butterflies_sparse(
     # sum each live wedge's rank within its run of equal keys — a run of
     # multiplicity m contributes 0 + 1 + ... + (m-1) = C(m, 2), which is
     # exactly the per-key butterfly count, summed without a segment scatter
-    wkey = jnp.sort(i1 * span_i + i2)           # dead wedges (>= n_i*span) last
+    # dead wedges (>= n_i * span) sort last
+    wkey = jax.lax.sort(i1 * span_i + i2, is_stable=False)
     wpos = jnp.arange(wedge_cap, dtype=jnp.int32)
-    head = (wpos == 0) | (wkey != jnp.roll(wkey, 1))
-    wstart = jax.lax.cummax(jnp.where(head, wpos, -1))
+    wstart = jnp.searchsorted(wkey, wkey, side="left").astype(jnp.int32)
     wrank = jnp.where(wkey < jnp.int32(n_i) * span_i, wpos - wstart, 0)
     return jnp.sum(wrank.astype(acc))
 

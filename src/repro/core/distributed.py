@@ -17,7 +17,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = ["distributed_count_dense", "make_distributed_window_counter"]
 
@@ -69,7 +68,7 @@ def distributed_count_dense(adj: jax.Array, mesh: Mesh, axis: str = "model",
             functools.partial(_pair_partial, block_rows=block_rows),
             half_ring=half_ring, wire_dtype=wire_dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=P(axis, None),
         out_specs=P(),
@@ -123,7 +122,7 @@ def make_distributed_window_counter(
 
         return jax.lax.map(one, (ei, ej, v))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_block, mesh=mesh,
         in_specs=(P(window_axis, None),) * 3,
         out_specs=P(window_axis),

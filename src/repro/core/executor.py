@@ -480,8 +480,6 @@ def _sharded_bucket_counter(tier: str, cap_i: int, cap_j: int, cap_w: int,
     windows, which every tier counts as 0)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..distributed.sharding import shard_map_compat
-
     chunk_fn = _chunk_counts_fn(tier, cap_i, cap_j, cap_w, tile,
                                 block_i, block_k, interpret, multiset,
                                 sampled)
@@ -489,11 +487,11 @@ def _sharded_bucket_counter(tier: str, cap_i: int, cap_j: int, cap_w: int,
 
     batch = axes if len(axes) > 1 else axes[0]
     n_lanes = 4 if (multiset or sampled is not None) else 3
-    fn = shard_map_compat(local, mesh,
-                          in_specs=(P(batch, None),) * n_lanes,
-                          out_specs=P(batch),
-                          # pallas_call has no replication rule to check
-                          check_rep=(tier != "pallas"))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(batch, None),) * n_lanes,
+                       out_specs=P(batch),
+                       # pallas_call has no varying-axes rule to check
+                       check_vma=(tier != "pallas"))
     return jax.jit(fn, donate_argnums=_donate_argnums(multiset, sampled))
 
 
@@ -965,6 +963,29 @@ class WindowExecutor:
             np.asarray(self._counter(b, multiset=multiset)(*lanes))
             done += 1
         return done
+
+    def staged_buckets(self) -> list[tuple[Bucket, bool, bool]]:
+        """Every bucket shape this executor has dispatched, as ``(bucket,
+        multiset, sampled)``: ``bucket.windows`` is ``arange(n)`` for its
+        ``n`` real windows, before any padding to the shard count."""
+        return [(Bucket(cap_e, cap_i, cap_j, np.arange(n), cap_w=cap_w),
+                 multiset, sampled)
+                for cap_e, cap_i, cap_j, cap_w, n, multiset, sampled
+                in sorted(self._staging)]
+
+    def program_text(self, b: Bucket, *, multiset: bool = False,
+                     sampled: bool = False) -> str:
+        """The compiled HLO text of the program that counts bucket ``b``,
+        lowered at the lanes a dispatch of it passes (the window axis
+        padded to the shard count, as :meth:`window_counts_submit` pads)."""
+        n = -(-b.n_windows // self.n_shards) * self.n_shards
+        lane = jax.ShapeDtypeStruct((n, b.cap_e), np.int32)
+        lanes = [lane] * (3 if multiset else 2)
+        if sampled:
+            lanes.append(jax.ShapeDtypeStruct((n, 2), np.uint32))
+        lanes.append(jax.ShapeDtypeStruct((n, b.cap_e), np.bool_))
+        counter = self._counter(b, multiset=multiset)
+        return counter.lower(*lanes).compile().as_text()
 
     def decrement_window_counts(self, per_window_edges, per_window_deletes,
                                 prior_counts, *, delta_frac: float = 0.25

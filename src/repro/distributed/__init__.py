@@ -1,3 +1,3 @@
-from .sharding import NO_SHARD, Sharder, batch_partition_axes, shard_map_compat
+from .sharding import NO_SHARD, Sharder, batch_partition_axes
 
-__all__ = ["Sharder", "NO_SHARD", "shard_map_compat", "batch_partition_axes"]
+__all__ = ["Sharder", "NO_SHARD", "batch_partition_axes"]

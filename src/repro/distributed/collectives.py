@@ -73,11 +73,7 @@ def ring_pair_count(a_block: jax.Array, axis_name: str, pair_fn,
     index wins.  wire_dtype (e.g. int8 for 0/1 adjacencies) compresses the
     permuted payload — count math still runs in fp32.
     """
-    # jax.lax.axis_size is a newer API; psum(1) is the portable spelling
-    if hasattr(jax.lax, "axis_size"):
-        n = jax.lax.axis_size(axis_name)
-    else:
-        n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     payload = a_block if wire_dtype is None else a_block.astype(wire_dtype)

@@ -22,31 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 NO_SHARD = None
 
-__all__ = ["Sharder", "NO_SHARD", "shard_map_compat", "batch_partition_axes"]
-
-
-def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs,
-                     check_rep: bool = True):
-    """``jax.shard_map`` when the installed jax exposes it (>= 0.6), the
-    ``jax.experimental.shard_map`` variant otherwise (feature-detect, not
-    version-parse — same policy as ``launch.mesh.make_mesh_compat``).
-
-    ``check_rep=False`` disables replication checking — required for bodies
-    containing ``pallas_call`` (no replication rule).  Newer jax renamed the
-    kwarg to ``check_vma``; both spellings are tried.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    if check_rep:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    for kw in ("check_rep", "check_vma"):
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{kw: False})
-        except TypeError:
-            continue
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+__all__ = ["Sharder", "NO_SHARD", "batch_partition_axes"]
 
 
 def batch_partition_axes(mesh: Mesh) -> tuple:

@@ -28,6 +28,11 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["butterfly_pairs_kernel_call", "butterfly_pairs_windows_kernel_call",
            "butterfly_pairs_windows_kernel_multiset_call"]
 
+# Mosaic takes no output block smaller than one (8, 128) f32 tile and cannot
+# store a scalar to VMEM: each tile pair's partial is broadcast over one such
+# tile, and the callers read element [0, 0].
+_OUT_TILE = (8, 128)
+
 
 def _triangle_pairs(nu: int):
     """Triangular tile-pair enumeration (u <= v) as scalar-prefetch arrays."""
@@ -65,7 +70,8 @@ def _kernel(upair_ref, vpair_ref, au_ref, av_ref, out_ref, acc_ref, *, nk: int, 
         # strict upper triangle in *global* i-indices:
         #   u < v  -> whole tile;  u == v -> row < col
         keep = (u * bi + row) < (v * bi + col)
-        out_ref[0, 0] = jnp.sum(jnp.where(keep, pairs, 0.0))
+        out_ref[...] = jnp.broadcast_to(
+            jnp.sum(jnp.where(keep, pairs, 0.0)), out_ref.shape)
 
 
 def butterfly_pairs_kernel_call(
@@ -95,7 +101,8 @@ def butterfly_pairs_kernel_call(
             pl.BlockSpec((block_i, block_k), lambda t, k, up, vp: (up[t], k)),
             pl.BlockSpec((block_i, block_k), lambda t, k, up, vp: (vp[t], k)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda t, k, up, vp: (t, 0)),
+        out_specs=pl.BlockSpec((1,) + _OUT_TILE,
+                               lambda t, k, up, vp: (t, 0, 0)),
         scratch_shapes=[pltpu.VMEM((block_i, block_i), jnp.float32)],
     )
     import functools
@@ -103,10 +110,10 @@ def butterfly_pairs_kernel_call(
     fn = pl.pallas_call(
         functools.partial(_kernel, nk=nk, bi=block_i),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, 1), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((T,) + _OUT_TILE, jnp.float32),
         interpret=interpret,
     )
-    return fn(upair, vpair, adj, adj)[:, 0]
+    return fn(upair, vpair, adj, adj)[:, 0, 0]
 
 
 def _windows_kernel(upair_ref, vpair_ref, au_ref, av_ref, out_ref, acc_ref,
@@ -138,7 +145,8 @@ def _windows_kernel(upair_ref, vpair_ref, au_ref, av_ref, out_ref, acc_ref,
         row = jax.lax.broadcasted_iota(jnp.int32, (bi, bi), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (bi, bi), 1)
         keep = (u * bi + row) < (v * bi + col)
-        out_ref[0, 0] = jnp.sum(jnp.where(keep, pairs, 0.0))
+        out_ref[...] = jnp.broadcast_to(
+            jnp.sum(jnp.where(keep, pairs, 0.0)), out_ref.shape)
 
 
 def butterfly_pairs_windows_kernel_call(
@@ -174,7 +182,8 @@ def butterfly_pairs_windows_kernel_call(
             pl.BlockSpec((1, block_i, block_k),
                          lambda b, t, k, up, vp: (b, vp[t], k)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, t, k, up, vp: (b, t)),
+        out_specs=pl.BlockSpec((1, 1) + _OUT_TILE,
+                               lambda b, t, k, up, vp: (b, t, 0, 0)),
         scratch_shapes=[pltpu.VMEM((block_i, block_i), jnp.float32)],
     )
     import functools
@@ -182,10 +191,10 @@ def butterfly_pairs_windows_kernel_call(
     fn = pl.pallas_call(
         functools.partial(_windows_kernel, nk=nk, bi=block_i),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, T) + _OUT_TILE, jnp.float32),
         interpret=interpret,
     )
-    return fn(upair, vpair, adjs, adjs)
+    return fn(upair, vpair, adjs, adjs)[:, :, 0, 0]
 
 
 def _windows_kernel_multiset(upair_ref, vpair_ref, au_ref, av_ref, out_ref,
@@ -228,7 +237,8 @@ def _windows_kernel_multiset(upair_ref, vpair_ref, au_ref, av_ref, out_ref,
         row = jax.lax.broadcasted_iota(jnp.int32, (bi, bi), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (bi, bi), 1)
         keep = (u * bi + row) < (v * bi + col)
-        out_ref[0, 0] = jnp.sum(jnp.where(keep, pairs, 0.0))
+        out_ref[...] = jnp.broadcast_to(
+            jnp.sum(jnp.where(keep, pairs, 0.0)), out_ref.shape)
 
 
 def butterfly_pairs_windows_kernel_multiset_call(
@@ -261,7 +271,8 @@ def butterfly_pairs_windows_kernel_multiset_call(
             pl.BlockSpec((1, block_i, block_k),
                          lambda b, t, k, up, vp: (b, vp[t], k)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, t, k, up, vp: (b, t)),
+        out_specs=pl.BlockSpec((1, 1) + _OUT_TILE,
+                               lambda b, t, k, up, vp: (b, t, 0, 0)),
         scratch_shapes=[pltpu.VMEM((block_i, block_i), jnp.float32),
                         pltpu.VMEM((block_i, block_i), jnp.float32)],
     )
@@ -270,7 +281,7 @@ def butterfly_pairs_windows_kernel_multiset_call(
     fn = pl.pallas_call(
         functools.partial(_windows_kernel_multiset, nk=nk, bi=block_i),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, T) + _OUT_TILE, jnp.float32),
         interpret=interpret,
     )
-    return fn(upair, vpair, adjs, adjs)
+    return fn(upair, vpair, adjs, adjs)[:, :, 0, 0]

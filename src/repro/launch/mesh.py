@@ -4,37 +4,19 @@ A FUNCTION, not a module-level constant — importing this module never touches
 jax device state (device count is locked at first jax init, and only
 dryrun.py sets the 512-host-device XLA flag).
 
-``make_mesh_compat`` papers over the ``jax.sharding.AxisType`` /
-``axis_types=`` API generation gap: newer jax wants explicit axis types on
-``jax.make_mesh`` while older releases (<= 0.4.x) have neither the enum nor
-the keyword.  Everything in this repo (and the subprocess test harnesses)
-builds meshes through it.
+Meshes use Auto axis types: ``jax.make_mesh`` defaults to Explicit ones,
+and the code here is written for shardings that the compiler propagates.
 """
 from __future__ import annotations
 
 import jax
 
-__all__ = ["make_mesh_compat", "make_production_mesh", "make_tiny_mesh",
-           "make_window_mesh"]
+__all__ = ["make_production_mesh", "make_tiny_mesh", "make_window_mesh"]
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with Auto axis types when the installed jax supports
-    them, plain otherwise (feature-detect, not version-parse).  Falls back to
-    ``Mesh(mesh_utils.create_device_mesh(...))`` on jax releases that predate
-    ``jax.make_mesh`` itself."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    from jax.experimental import mesh_utils
-
-    return jax.sharding.Mesh(mesh_utils.create_device_mesh(shape), axes)
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_window_mesh(devices=None, *, axis: str = "data"):
@@ -44,8 +26,8 @@ def make_window_mesh(devices=None, *, axis: str = "data"):
     ``devices`` is an int (the first N of ``jax.devices()``), an explicit
     device sequence, or None for every device.  The axis is named "data" so
     ``distributed.sharding.Sharder`` / ``batch_partition_axes`` resolve it as
-    data-parallel.  Prefix meshes (N < device count) bypass ``make_mesh_compat``
-    — ``jax.make_mesh`` insists on consuming every device.
+    data-parallel.  Prefix meshes (N < device count) are built from the
+    device list directly, in ``jax.devices()`` order.
     """
     import numpy as np
 
@@ -62,7 +44,7 @@ def make_window_mesh(devices=None, *, axis: str = "data"):
         if not devs:
             raise ValueError("empty device sequence")
     if devs == avail:
-        return make_mesh_compat((len(devs),), (axis,))
+        return _auto_mesh((len(devs),), (axis,))
     return jax.sharding.Mesh(np.asarray(devs), (axis,))
 
 
@@ -71,11 +53,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     2-way "pod" axis (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_tiny_mesh(*, multi_pod: bool = False):
     """Reduced mesh for CI-scale dry-run validation (8 host devices)."""
     shape = (2, 2, 2) if multi_pod else (2, 4)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return _auto_mesh(shape, axes)

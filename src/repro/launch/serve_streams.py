@@ -19,6 +19,7 @@ import asyncio
 import logging
 import signal
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.streams.config import EngineConfig, ServingConfig
 from repro.streams.faults import install_from_env
 from repro.streams.server import StreamServer, TenantPolicy
@@ -118,6 +119,7 @@ def main() -> None:
     # crash legs ship their fault plan via $SGRAPP_FAULT_PLAN; a no-op
     # otherwise (repro.streams.faults)
     install_from_env()
+    use_compile_cache()
     asyncio.run(run(args))
 
 

@@ -162,8 +162,6 @@ def eqv2_loss_halo(params, batch, cfg: EqV2Config, mesh, axes: tuple):
     [n_dev, e_loc, nc, nc]; labels_2d/label_mask_2d [n_dev, n_loc].
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
-
     from ...graphs.halo import halo_exchange
 
     nc, c = cfg.n_coeff, cfg.d_hidden
@@ -216,7 +214,7 @@ def eqv2_loss_halo(params, batch, cfg: EqV2Config, mesh, axes: tuple):
         den = jax.lax.psum(lmask.sum(), axes)
         return num / jnp.maximum(den, 1.0)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes, None), P(axes, None, None), P(axes, None),
                   P(axes, None), P(axes, None), P(axes, None, None, None),

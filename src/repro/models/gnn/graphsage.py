@@ -79,8 +79,6 @@ def sage_loss_halo(params, batch, cfg: SAGEConfig, mesh, axes: tuple):
     import functools
 
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
-
     from ...graphs.halo import halo_exchange
     from ...graphs.segment import segment_mean as _segment_mean
 
@@ -103,7 +101,7 @@ def sage_loss_halo(params, batch, cfg: SAGEConfig, mesh, axes: tuple):
         den = jax.lax.psum(lmask.sum(), axes)
         return num / jnp.maximum(den, 1.0)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes, None), P(axes, None, None), P(axes, None),
                   P(axes, None), P(axes, None), P(axes, None), P(axes, None)),

@@ -331,7 +331,11 @@ class ServerProcess:
     """``repro.launch.serve_streams`` in a subprocess, with the ephemeral
     data/http ports parsed from its stdout and a fault plan shipped via
     ``$SGRAPP_FAULT_PLAN``.  SIGKILL-able by plan or by hand
-    (:meth:`kill`); context-manager cleanup never leaves orphans."""
+    (:meth:`kill`); context-manager cleanup never leaves orphans.
+
+    The child inherits this process's environment plus ``env``, so it runs
+    on the platform JAX picks there.  A chip belongs to one process: a
+    parent that has initialised a JAX backend on it must not start one."""
 
     def __init__(self, *, nt_w: int, alpha0: float, tenants: dict,
                  checkpoint_dir: str, tier: str = "numpy",
@@ -350,7 +354,6 @@ class ServerProcess:
             cmd += ["--checkpoint-every-s", str(checkpoint_every_s)]
         cmd += list(extra_args or [])
         penv = dict(os.environ)
-        penv.setdefault("JAX_PLATFORMS", "cpu")
         # .../src/repro/streams/faults.py -> .../src
         src = os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))

@@ -158,9 +158,33 @@ def bipartite_pa_stream(
     else:
         raise ValueError(f"unknown temporal mode {temporal!r}")
     if n_unique is not None:
-        qs = np.quantile(tau, np.linspace(0, 1, n_unique))
+        qs = _sorted_quantiles(tau, np.linspace(0, 1, n_unique))
         tau = qs[np.clip(np.searchsorted(qs, tau), 0, n_unique - 1)]
     return SgrStream(tau, eu, ei)
+
+
+def _sorted_quantiles(x: np.ndarray, q: np.ndarray) -> np.ndarray:
+    """``np.quantile(x, q)`` (linear method) of an already sorted ``x``, bit
+    for bit: numpy's own index and interpolation arithmetic, without the
+    partition it runs first, which costs O(len(x) * len(q)) — about 25 s
+    for 131072 timestamps quantized to 65536 values."""
+    n = x.shape[0]
+    virtual = (n - 1) * q
+    prev = np.floor(virtual)
+    nxt = prev + 1
+    above = virtual >= n - 1
+    prev[above] = -1
+    nxt[above] = -1
+    below = virtual < 0
+    prev[below] = 0
+    nxt[below] = 0
+    prev = prev.astype(np.intp)
+    gamma = virtual - prev
+    a, b = x[prev], x[nxt.astype(np.intp)]
+    diff = b - a
+    out = a + diff * gamma
+    np.subtract(b, diff * (1 - gamma), out=out, where=gamma >= 0.5)
+    return out
 
 
 def dynamic_sgr_stream(

@@ -479,7 +479,7 @@ def _sigkill_leg(tmp_path, plan: FaultPlan, *, n_batches: int = 16,
              *server_args]
     srv_kw = dict(nt_w=NT_W, alpha0=ALPHA0, tenants={"t0": 0},
                   checkpoint_dir=ckpt, tier=tier, flush_ms=1.0,
-                  extra_args=fixed)
+                  extra_args=fixed, env={"JAX_PLATFORMS": "cpu"})
 
     async def scenario():
         client = DurableClient("127.0.0.1", port, "t0")

@@ -46,7 +46,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.models.gnn.graphsage import SAGEConfig, init_sage, sage_loss, sage_loss_halo
 from repro.graphs.halo import build_partitioned_batch
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_tiny_mesh
 rng = np.random.default_rng(0)
 n_dev, n, e = 8, 64, 400
 src = rng.integers(0, n, e)
@@ -58,7 +58,7 @@ labels = rng.integers(0, 5, n)
 cfg = SAGEConfig(name="s", d_in=16, d_hidden=8, n_classes=5)
 params = init_sage(jax.random.PRNGKey(0), cfg)
 pg = build_partitioned_batch(src, dst, x, labels, n_dev, halo=64)
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+mesh = make_tiny_mesh()
 bh = {k: jnp.asarray(v) for k, v in pg.device_batch().items()}
 with mesh:
     lh = float(jax.jit(lambda p, b: sage_loss_halo(p, b, cfg, mesh, ("data","model")))(params, bh))
@@ -82,7 +82,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.models.gnn.equiformer_v2 import EqV2Config, init_eqv2, eqv2_loss, eqv2_loss_halo
 from repro.graphs.halo import build_partitioned_batch
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_tiny_mesh
 rng = np.random.default_rng(0)
 n_dev, n, e = 8, 64, 300
 src = rng.integers(0, n, e)
@@ -105,7 +105,7 @@ wig_p = np.zeros((n_dev, e_cap, nc, nc), np.float32)
 for d_ in range(n_dev):
     for slot in range(min(counts[d_], e_cap)):
         wig_p[d_, slot] = wig_global[order[(d_, slot)]]
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+mesh = make_tiny_mesh()
 bh = {k: jnp.asarray(v) for k, v in pg.device_batch().items()}
 bh["wigner"] = jnp.asarray(wig_p)
 with mesh:

@@ -65,8 +65,8 @@ from repro.core.distributed import make_distributed_window_counter
 from repro.core.windows import windowize
 from repro.core.sgrapp import window_exact_counts
 from repro.streams import bipartite_pa_stream
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+from repro.launch.mesh import make_tiny_mesh
+mesh = make_tiny_mesh()
 s = bipartite_pa_stream(1500, seed=1, n_unique=300)
 wb = windowize(s.tau, s.edge_i, s.edge_j, 50)
 nw = (wb.n_windows // 2) * 2
@@ -95,10 +95,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.train.checkpoint import save_checkpoint, restore_checkpoint
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_tiny_mesh
 
 d = r"{str(tmp_path / 'ck')}"
-mesh_a = make_mesh_compat((2, 4), ("data", "model"))
+mesh_a = make_tiny_mesh()
 params = {{"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
            "b": jnp.ones((16,), jnp.float32)}}
 sharded = {{
@@ -108,7 +108,8 @@ sharded = {{
 save_checkpoint(d, 1, sharded)
 
 # 'restart' on a different mesh shape with transposed layout
-mesh_b = make_mesh_compat((4, 2), ("data", "model"))
+mesh_b = jax.make_mesh((4, 2), ("data", "model"),
+                       axis_types=(jax.sharding.AxisType.Auto,) * 2)
 shardings = {{
     "w": NamedSharding(mesh_b, P(None, "data")),
     "b": NamedSharding(mesh_b, P("model")),

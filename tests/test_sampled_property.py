@@ -149,8 +149,11 @@ def test_gamma_ladder_is_the_minimal_rung(t, gamma):
     k, p = int(k), float(p)
     g32 = np.float32(gamma)
     t32 = np.float32(t)
+    # the device flushes subnormal results to zero, numpy keeps them
+    power = g32 ** np.float32(k)
+    power = power if power >= np.finfo(np.float32).tiny else np.float32(0)
     assert k >= 0
-    assert np.float32(p) == g32 ** np.float32(k)
+    assert np.float32(p) == power
     if t32 >= 1.0:
         assert (k, p) == (0, 1.0)
     elif p > 0.0:

@@ -144,6 +144,24 @@ def test_plan_partitions_all_windows():
     assert len(buckets) > 1
 
 
+def test_staged_buckets_report_the_dispatched_shapes():
+    """``staged_buckets`` lists each dispatched bucket shape once, with its
+    real window count; ``program_text`` compiles the program that ran it."""
+    batch = batch_of(ADVERSARIAL.values())
+    ex = WindowExecutor("dense", align=8)
+    assert ex.staged_buckets() == []
+    ex.window_counts(batch)
+    ex.window_counts(batch)
+    want = sorted((b.cap_e, b.cap_i, b.cap_j, b.n_windows)
+                  for b in ex.plan(batch))
+    staged = ex.staged_buckets()
+    assert sorted((b.cap_e, b.cap_i, b.cap_j, b.n_windows)
+                  for b, _, _ in staged) == want
+    assert not any(multiset or sampled for _, multiset, sampled in staged)
+    b = staged[0][0]
+    assert f"s32[{b.n_windows},{b.cap_e}]" in ex.program_text(b)
+
+
 def test_bucket_caps_never_exceed_global_capacity():
     """A window whose ladder rung overshoots the batch's padded capacity
     (e.g. ~300 i-vertices: rung 512 > global 384) must clamp to it — the
