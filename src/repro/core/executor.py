@@ -111,6 +111,7 @@ from .butterfly import (
     window_wedge_counts_np,
 )
 from .fleet import check_sampling_knobs
+from .telemetry import Telemetry
 from .windows import WindowBatch
 
 __all__ = ["TIERS", "MODES", "WindowExecutor", "ExecutorResult", "Bucket",
@@ -220,7 +221,9 @@ class Bucket:
     ``cap_w`` is the wedge capacity — the ladder rung over the bucket's
     max per-window deduped wedge count.  It is only computed (non-zero) for
     the ``sparse`` / ``auto`` tiers, where it sizes the wedge-sort scratch
-    and feeds the auto router's cost model.
+    and feeds the auto router's cost model.  ``wedges`` is the sum of the
+    bucket's windows' deduped wedge counts (same tiers), the part of their
+    ``cap_w`` lanes that holds real wedges.
     """
 
     cap_e: int                      # edge-lane capacity
@@ -228,6 +231,7 @@ class Bucket:
     cap_j: int                      # j-side id-space capacity
     windows: np.ndarray = field(compare=False)  # window indices in the batch
     cap_w: int = 0                  # wedge capacity (sparse/auto tiers only)
+    wedges: int = field(default=0, compare=False)
 
     @property
     def n_windows(self) -> int:
@@ -678,6 +682,9 @@ class WindowExecutor:
         # same-shape submit, and at most one handle is in flight at a time
         # (see PendingCounts), so alternating two buffers is sufficient.
         self._staging: dict[tuple, list] = {}
+        # phase timers and counters (repro.core.telemetry); the engine
+        # shares this table, and a server replaces it with its own
+        self.telemetry = Telemetry()
 
     # -- planning -----------------------------------------------------------
 
@@ -751,7 +758,9 @@ class WindowExecutor:
                 cap_j = min(id_capacity(
                     int(batch.n_j_per_window[win].max()), align=self.snap),
                     cap_j)
-            buckets.append(Bucket(cap_e, cap_i, cap_j, win, cap_w=cap_w))
+            buckets.append(Bucket(
+                cap_e, cap_i, cap_j, win, cap_w=cap_w,
+                wedges=int(wedges[win].sum()) if wedges is not None else 0))
         self._plan_cache = (weakref.ref(batch), buckets)
         return buckets
 
@@ -895,25 +904,42 @@ class WindowExecutor:
                 "an exact tier for multiset streams")
         uids = self._batch_uids(batch) if self.tier == "sampled" else None
         parts: list = []
-        if self.tier == "numpy":
-            for b in self.plan(batch):
-                counts = np.empty(b.n_windows, dtype=np.float64)
-                for t, k in enumerate(b.windows):
-                    v = batch.valid[k]
-                    e = np.stack([batch.edge_i[k][v], batch.edge_j[k][v]],
-                                 axis=1)
-                    counts[t] = (count_butterflies_multiset_np(
-                        e, batch.edge_mult[k][v]) if multiset
-                        else count_butterflies_np(e))
-                parts.append((b.windows, counts))
-            return PendingCounts(batch.n_windows, parts)
         for b in self.plan(batch):
-            lanes = self._staged_lanes(batch, b, multiset, uids)
-            if self.n_shards > 1:
-                lanes = _pad_window_axis(*lanes, multiple=self.n_shards)
-            counts = self._counter(b, multiset=multiset)(*lanes)  # async
+            tier = self.bucket_tier(b)
+            # one span per bucket: a compile inside the window shows as a
+            # long launch naming its shape
+            with self.telemetry.phase("launch", timer="submit", tier=tier,
+                                      cap_e=b.cap_e, cap_i=b.cap_i,
+                                      cap_j=b.cap_j, cap_w=b.cap_w):
+                if tier == "numpy":
+                    counts = self._count_on_host(batch, b, multiset)
+                else:
+                    lanes = self._staged_lanes(batch, b, multiset, uids)
+                    if self.n_shards > 1:
+                        lanes = _pad_window_axis(*lanes,
+                                                 multiple=self.n_shards)
+                    counts = self._counter(b, multiset=multiset)(
+                        *lanes)   # async
             parts.append((b.windows, counts))
+            if tier == "sparse":
+                # each window gets a cap_w-wide wedge lane; the rest of
+                # what it holds beyond the window's own wedges is padding
+                self.telemetry.add("wedge_lanes", b.cap_w * b.n_windows)
+                self.telemetry.add("wedges", b.wedges)
         return PendingCounts(batch.n_windows, parts)
+
+    @staticmethod
+    def _count_on_host(batch: WindowBatch, b: Bucket,
+                       multiset: bool) -> np.ndarray:
+        """The ``numpy`` tier's counts of one bucket, computed eagerly."""
+        counts = np.empty(b.n_windows, dtype=np.float64)
+        for t, k in enumerate(b.windows):
+            v = batch.valid[k]
+            e = np.stack([batch.edge_i[k][v], batch.edge_j[k][v]], axis=1)
+            counts[t] = (count_butterflies_multiset_np(
+                e, batch.edge_mult[k][v]) if multiset
+                else count_butterflies_np(e))
+        return counts
 
     def window_counts(self, batch: WindowBatch) -> np.ndarray:
         """Exact in-window count per tumbling window, [n_windows] float64.

@@ -202,6 +202,9 @@ class MultiStreamSGrapp:
         # stream engine: flushes see the streams piecewise, bucket programs
         # must compile at ladder rungs and never re-trace at steady state
         self.executor = cfg.make_executor(executor)
+        # phase timers and dispatch counters, shared with the executor; a
+        # server replaces both references with its own table
+        self.telemetry = self.executor.telemetry
         self._step_fn = estimator_step(cfg.tol, cfg.step)
         # async overlapped flush pipeline, exactly as the single-stream
         # engine: push() submits without blocking, the next flush point
@@ -335,9 +338,10 @@ class MultiStreamSGrapp:
                 raise NotImplementedError(
                     "the sampled tier does not support edge deletions: "
                     "reservoir estimates are insert-only (FLEET)")
-        closed = windowizer_push(self._state, stream_id, tau, edge_i, edge_j,
-                                 self.nt_w, op=op,
-                                 on_missing_delete=self.on_missing_delete)
+        with self.telemetry.phase("windowize"):
+            closed = windowizer_push(self._state, stream_id, tau, edge_i,
+                                     edge_j, self.nt_w, op=op,
+                                     on_missing_delete=self.on_missing_delete)
         for s, ei, ej, ops, m, end_tau in closed:
             self._pending[s].append((ei, ej, ops, m, end_tau))
             self._pending_streams.add(s)
@@ -366,6 +370,29 @@ class MultiStreamSGrapp:
         if self._n_pending_total == 0:
             return False
         assert self._inflight is None, "reap the in-flight flush first"
+        with self.telemetry.phase("pack", timer="submit"):
+            streams, batch, cum, end_tau = self._pack_pending()
+            # planned inside the span; the submit reuses the memoized plan
+            self.executor.plan(batch)
+        handle = self.executor.window_counts_submit(batch)
+        # windows stay pending until dispatched: a packing error (one
+        # tenant's bad edge ids) raises above with every pending list
+        # intact, so the whole fleet stays consistent and the next flush
+        # retries instead of silently dropping closed windows
+        n_per_stream = [len(self._pending[s]) for s in streams]
+        for s in streams:
+            self._pending[s] = []
+        self._pending_streams.clear()
+        self._n_pending_total = 0
+        self.telemetry.add("dispatch_count")
+        self.telemetry.add("windows_dispatched", len(cum))
+        self._inflight = (streams, n_per_stream, handle, cum, end_tau)
+        return True
+
+    def _pack_pending(self) -> tuple:
+        """Resolve and pack every tenant's pending closed windows into ONE
+        batch with the stream-id provenance lane; returns ``(streams,
+        batch, cum, end_tau)``."""
         streams = sorted(self._pending_streams)
         per_edges: list[np.ndarray] = []
         per_mult: list[np.ndarray | None] = []
@@ -408,18 +435,7 @@ class MultiStreamSGrapp:
                 window_end_tau=np.asarray(end_tau, dtype=np.float64),
                 align=self.align, stream_ids=np.asarray(sids, dtype=np.int32),
                 sample_uid=uid)
-        handle = self.executor.window_counts_submit(batch)
-        # windows stay pending until dispatched: a packing error (one
-        # tenant's bad edge ids) raises above with every pending list
-        # intact, so the whole fleet stays consistent and the next flush
-        # retries instead of silently dropping closed windows
-        n_per_stream = [len(self._pending[s]) for s in streams]
-        for s in streams:
-            self._pending[s] = []
-        self._pending_streams.clear()
-        self._n_pending_total = 0
-        self._inflight = (streams, n_per_stream, handle, cum, end_tau)
-        return True
+        return streams, batch, cum, end_tau
 
     def _reap_flush(self) -> int:
         """Reap half of the fleet flush: block on the in-flight dispatch's
@@ -430,8 +446,21 @@ class MultiStreamSGrapp:
         if self._inflight is None:
             return 0
         streams, n_per_stream, handle, cum, end_tau = self._inflight
-        counts = handle.reap()   # float64 [m]
-        self._inflight = None
+        with self.telemetry.phase("count_wait"):
+            counts = handle.reap()   # float64 [m]
+        try:
+            with self.telemetry.phase("estimate", timer="estimator"):
+                self._advance_estimators(streams, n_per_stream, counts, cum,
+                                         end_tau)
+        finally:
+            # in flight until the history holds its windows: a reader on
+            # another thread (the server's /metrics) that sees nothing in
+            # flight then sees every counted window
+            self._inflight = None
+        return len(counts)
+
+    def _advance_estimators(self, streams, n_per_stream, counts, cum,
+                            end_tau) -> None:
         # scatter counts back per tenant: windows were appended stream by
         # stream in ascending id, so each tenant's windows are a contiguous
         # slice, in close order (the batch's stream_ids lane records the
@@ -450,7 +479,6 @@ class MultiStreamSGrapp:
             set_estimator_carry(self._state, s, carry)
             self._state.total_sgrs[s] = int(cum[off + n_new - 1])
             off += n_new
-        return len(counts)
 
     def flush(self) -> int:
         """Count every closed-but-uncounted window fleet-wide — the

@@ -55,11 +55,12 @@ travels on the wire (see :mod:`repro.streams.wire`), so a tenant cannot
 write into another tenant's stream.  Per-tenant admission is a token-bucket
 rate limit (records/s + burst) plus an oversized-batch cap.
 
-Observability: per-tenant and aggregate counters, a push-latency histogram
-(p50/p99 over a sliding reservoir), and queue depth — exported as JSON on
-``GET /metrics`` of a second (HTTP) port, with ``GET /healthz`` for
-liveness.  Request handling emits structured JSON logs on the
-``repro.streams.server`` logger.
+Observability: per-tenant and aggregate counters, fixed-bucket histograms
+of the engine cycle and the reap wait, one phase timer per host step of the
+served path (:mod:`repro.core.telemetry`, each step also a ``sgrapp.*``
+profiler span), and queue depth — exported as JSON on ``GET /metrics`` of a
+second (HTTP) port, with ``GET /healthz`` for liveness.  Request handling
+emits structured JSON logs on the ``repro.streams.server`` logger.
 
 Durability: every admitted push is appended to a per-tenant write-ahead log
 (:mod:`repro.streams.wal`) keyed by its monotonic ``seq`` and group-commit
@@ -81,15 +82,14 @@ exactly between WAL-fsync and ack, or mid-checkpoint-rename.
 from __future__ import annotations
 
 import asyncio
-import bisect
 import json
 import logging
 import os
 import time
-from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
+from repro.core.telemetry import Telemetry, Timer
 from repro.streams.config import EngineConfig, ServingConfig
 from repro.streams.multi import MultiStreamSGrapp
 from repro.streams.wal import FleetWAL, WALCorruption, WALError
@@ -111,9 +111,6 @@ REJECT_BACKPRESSURE = "backpressure"
 REJECT_ENGINE = "engine_reject"
 REJECT_WAL = "wal_error"
 REJECT_INTERNAL = "internal"
-
-_LATENCY_BOUNDS_MS = (0.5, 1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0, 200.0,
-                      500.0, 1000.0)
 
 
 @dataclass(frozen=True)
@@ -168,9 +165,11 @@ class _TenantCounters:
 
 
 class ServerMetrics:
-    """Aggregate + per-tenant serving counters and the push-latency
-    histogram.  ``snapshot()`` is the ``/metrics`` JSON body — the schema is
-    documented in docs/serving.md and pinned by the serving tests."""
+    """Aggregate + per-tenant serving counters, the engine-cycle and
+    reap-wait histograms, and the phase-timer table the server hands to its
+    engine and executor.  ``snapshot()`` is the ``/metrics`` JSON body — the
+    schema is documented in docs/serving.md and pinned by the serving
+    tests."""
 
     def __init__(self, stream_ids):
         self.tenants = {int(s): _TenantCounters() for s in stream_ids}
@@ -185,55 +184,18 @@ class ServerMetrics:
         self.wal_errors = 0                   # WAL append/sync failures
         self.checkpoint_failures = 0          # failed checkpoint attempts
         self.checkpoint_fallbacks = 0         # corrupt steps skipped at boot
-        # async flush pipeline observability (ISSUE: overlap must be
-        # visible in serving, not just in benches)
-        self.dispatch_count = 0               # async bucketed dispatches
-        self.windows_dispatched = 0           # windows across them
-        self._reap_count = 0
-        self._reap_sum_ms = 0.0
-        self._reap_recent = deque(maxlen=4096)
-        self._lat_count = 0
-        self._lat_sum_ms = 0.0
-        self._lat_max_ms = 0.0
-        self._lat_buckets = [0] * (len(_LATENCY_BOUNDS_MS) + 1)
-        self._lat_recent = deque(maxlen=4096)  # sliding p50/p99 reservoir
+        self.push_latency_ms = Timer()        # engine cycles (event loop)
+        self.reap_wait_ms = Timer()           # server reaps (engine thread)
+        # phase timers, dispatch and wedge-lane counters: written by the
+        # event loop, the engine and the executor alike
+        self.telemetry = Telemetry()
 
     def observe_push_latency(self, ms: float, n_items: int) -> None:
         self.pushes += 1
         self.coalesced_items += n_items
-        self._lat_count += 1
-        self._lat_sum_ms += ms
-        self._lat_max_ms = max(self._lat_max_ms, ms)
-        self._lat_buckets[bisect.bisect_left(_LATENCY_BOUNDS_MS, ms)] += 1
-        self._lat_recent.append(ms)
-
-    def observe_dispatch(self, n_windows: int) -> None:
-        self.dispatch_count += 1
-        self.windows_dispatched += int(n_windows)
-
-    def observe_reap_wait(self, ms: float) -> None:
-        self._reap_count += 1
-        self._reap_sum_ms += ms
-        self._reap_recent.append(ms)
-
-    @staticmethod
-    def _pct(recent, q: float) -> float:
-        if not recent:
-            return 0.0
-        xs = sorted(recent)
-        k = min(len(xs) - 1, int(round(q * (len(xs) - 1))))
-        return float(xs[k])
-
-    def percentile(self, q: float) -> float:
-        return self._pct(self._lat_recent, q)
-
-    def reap_percentile(self, q: float) -> float:
-        return self._pct(self._reap_recent, q)
+        self.push_latency_ms.observe(ms)
 
     def snapshot(self, **extra) -> dict:
-        buckets = {f"<={b}ms": c for b, c in
-                   zip(_LATENCY_BOUNDS_MS, self._lat_buckets)}
-        buckets[f">{_LATENCY_BOUNDS_MS[-1]}ms"] = self._lat_buckets[-1]
         agg = _TenantCounters()
         for t in self.tenants.values():
             agg.edges_accepted += t.edges_accepted
@@ -243,6 +205,9 @@ class ServerMetrics:
             agg.windows_closed += t.windows_closed
             for r, c in t.rejects.items():
                 agg.rejects[r] = agg.rejects.get(r, 0) + c
+        tel = self.telemetry
+        dispatches = tel.count("dispatch_count")
+        windows = tel.count("windows_dispatched")
         out = {
             "aggregate": {
                 "edges_accepted": agg.edges_accepted,
@@ -257,27 +222,15 @@ class ServerMetrics:
                 "engine_errors": self.engine_errors,
                 "flush_errors": self.flush_errors,
                 "internal_errors": self.internal_errors,
-                "dispatch_count": self.dispatch_count,
-                "windows_dispatched": self.windows_dispatched,
+                "dispatch_count": dispatches,
+                "windows_dispatched": windows,
                 "coalesced_windows_per_dispatch": (
-                    self.windows_dispatched / self.dispatch_count
-                    if self.dispatch_count else 0.0),
-                "reap_wait_ms": {
-                    "count": self._reap_count,
-                    "mean": (self._reap_sum_ms / self._reap_count
-                             if self._reap_count else 0.0),
-                    "p50": self.reap_percentile(0.50),
-                    "p99": self.reap_percentile(0.99),
-                },
-                "push_latency_ms": {
-                    "count": self._lat_count,
-                    "mean": (self._lat_sum_ms / self._lat_count
-                             if self._lat_count else 0.0),
-                    "p50": self.percentile(0.50),
-                    "p99": self.percentile(0.99),
-                    "max": self._lat_max_ms,
-                    "buckets": buckets,
-                },
+                    windows / dispatches if dispatches else 0.0),
+                "reap_wait_ms": self.reap_wait_ms.snapshot(),
+                "push_latency_ms": self.push_latency_ms.snapshot(),
+                "phases_ms": tel.timers(),
+                "executor": {"wedge_lanes": tel.count("wedge_lanes"),
+                             "wedges": tel.count("wedges")},
             },
             "tenants": {
                 str(s): {
@@ -421,6 +374,8 @@ class StreamServer:
             wal_dir = os.path.join(checkpoint_dir, "wal")
         self.wal_dir = wal_dir
         self.metrics = ServerMetrics(range(self.n_streams))
+        self.engine.telemetry = self.engine.executor.telemetry = \
+            self.metrics.telemetry
 
         self._buckets = {
             tok: _TokenBucket(
@@ -698,11 +653,12 @@ class StreamServer:
 
     def _collect_updates(self) -> dict:
         ups = {}
-        for s in range(self.n_streams):
-            n = self.engine.n_counted(s)
-            if n > self._published[s]:
-                ups[s] = self.engine.history(s, self._published[s])
-                self._published[s] = n
+        with self.metrics.telemetry.phase("collect"):
+            for s in range(self.n_streams):
+                n = self.engine.n_counted(s)
+                if n > self._published[s]:
+                    ups[s] = self.engine.history(s, self._published[s])
+                    self._published[s] = n
         return ups
 
     def _apply_records(self, s: int, rb: RecordBatch) -> dict:
@@ -724,7 +680,8 @@ class StreamServer:
         s = it.stream_id
         if self._wal is not None:
             try:
-                self._wal.append(s, it.seq, it.rb)
+                with self.metrics.telemetry.phase("wal_append"):
+                    self._wal.append(s, it.seq, it.rb)
             except WALError as e:
                 # nothing acked durable: reject so the client retries after
                 # the disk recovers; watermark does NOT advance
@@ -747,6 +704,10 @@ class StreamServer:
         return out
 
     def _engine_apply(self, items: list) -> tuple[list, dict]:
+        tel = self.metrics.telemetry
+        now = time.monotonic()
+        for it in items:
+            tel.observe("queue_wait", (now - it.t_enqueue) * 1e3)
         outs = []
         for it in items:
             s = it.stream_id
@@ -768,7 +729,8 @@ class StreamServer:
                          for o in outs)
         if self._wal is not None:
             try:
-                self._wal.sync()
+                with tel.phase("wal_sync"):
+                    self._wal.sync()
                 if not wal_failed:   # a clean full cycle clears degraded
                     self._clear_degraded("wal")
             except WALError as e:
@@ -787,13 +749,16 @@ class StreamServer:
         return outs, self._collect_updates()
 
     def _reap_now(self) -> int:
-        """Reap the in-flight dispatch (engine thread).  The measured wait
-        is exactly the non-overlapped remainder of the device compute."""
+        """Reap the in-flight dispatch (engine thread).  ``reap_wait_ms``
+        times the whole reap: the non-overlapped remainder of the device
+        compute plus the estimator steps.  Reaps that ``engine.push`` makes
+        itself are not seen here; the ``count_wait`` and ``estimator``
+        phases see every reap."""
         if not self.engine.n_inflight:
             return 0
         t0 = time.monotonic()
         n = self.engine._reap_flush()
-        self.metrics.observe_reap_wait((time.monotonic() - t0) * 1e3)
+        self.metrics.reap_wait_ms.observe((time.monotonic() - t0) * 1e3)
         return n
 
     def _engine_dispatch(self) -> None:
@@ -816,8 +781,7 @@ class StreamServer:
         budget_s = self.latency_budget_ms / 1000.0
         if budget_s > 0.0 and (now - self._pending_since) < budget_s:
             return   # defer: the coalescer's deadline timer fires us later
-        if self.engine._submit_flush():
-            self.metrics.observe_dispatch(self.engine.n_inflight)
+        self.engine._submit_flush()
         self._pending_since = None
 
     def _engine_dispatch_collect(self) -> dict:
@@ -851,13 +815,18 @@ class StreamServer:
     def _save_checkpoint(self) -> None:
         from repro.train.checkpoint import latest_step, save_checkpoint
 
+        # settle outstanding windows first, outside the checkpoint span,
+        # so the flush's own spans do not nest inside it
+        self.engine.flush()
         prev = latest_step(self.checkpoint_dir)
         step = 0 if prev is None else int(prev) + 1
         # state_dict + watermarks snapshot on the same (engine) thread, so
         # the saved watermark is exactly the state's last applied seq
-        save_checkpoint(self.checkpoint_dir, step, self.engine.state_dict(),
-                        extra={"published": list(self._published),
-                               "watermarks": list(self._watermarks)})
+        with self.metrics.telemetry.phase("checkpoint"):
+            save_checkpoint(self.checkpoint_dir, step,
+                            self.engine.state_dict(),
+                            extra={"published": list(self._published),
+                                   "watermarks": list(self._watermarks)})
         self._last_ckpt_t = time.monotonic()
         if self._wal is not None:
             removed = self._wal.gc(self._gc_marks)
@@ -981,6 +950,12 @@ class StreamServer:
             self._log("reap_error", error=repr(e))
 
     def _fanout_estimates(self, updates: dict) -> None:
+        if not updates:
+            return
+        with self.metrics.telemetry.phase("fanout"):
+            self._fanout(updates)
+
+    def _fanout(self, updates: dict) -> None:
         for s, h in updates.items():
             if not self._subscribers[s]:
                 continue
@@ -1100,7 +1075,8 @@ class StreamServer:
             await reject(REJECT_DRAINING, 0)
             return
         try:
-            rb = records_from_json(msg.get("records"), stream_id=s)
+            with self.metrics.telemetry.phase("decode"):
+                rb = records_from_json(msg.get("records"), stream_id=s)
         except ValueError as e:
             await reject(REJECT_BAD_RECORDS, 0, detail=str(e))
             return
@@ -1141,7 +1117,7 @@ class StreamServer:
             seq = self._seq_hwm[s] + 1   # legacy client: server-assigned
         fut = self._loop.create_future()
         try:
-            self._queue.put_nowait(_Item(s, rb, fut, t0, seq))
+            self._queue.put_nowait(_Item(s, rb, fut, time.monotonic(), seq))
         except asyncio.QueueFull:
             # hwm intentionally NOT advanced: a backpressure reject must
             # not burn the seq the client will retry with
@@ -1344,7 +1320,9 @@ class StreamServer:
     # -- structured logs -----------------------------------------------------
 
     def _log(self, event: str, **kv) -> None:
-        log.info("%s", json.dumps({"event": event, **kv}, sort_keys=True))
+        if log.isEnabledFor(logging.INFO):
+            log.info("%s", json.dumps({"event": event, **kv},
+                                      sort_keys=True))
 
 
 def _encode(obj: dict) -> bytes:

@@ -15,6 +15,8 @@ from __future__ import annotations
 
 import asyncio
 import json
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -381,6 +383,135 @@ def test_healthz_metrics_and_404():
         await server.stop(checkpoint=False)
 
     asyncio.run(scenario())
+
+
+def test_metrics_settles_a_reap_in_progress():
+    """``/metrics`` reports every window of a dispatch whose follow-up reap
+    is still advancing the estimators when the scrape arrives."""
+    stream = tenant_streams(1)[0]
+
+    async def scenario():
+        server = await StreamServer(nt_w=NT_W, alpha0=ALPHA0,
+                                    tenants={"t0": 0}, config=CFG).start()
+        step = server.engine._step_fn
+
+        def slow_step(carry, xs):
+            time.sleep(0.05)
+            return step(carry, xs)
+
+        server.engine._step_fn = slow_step
+        c = await Client.connect(server, "t0")
+        reply = await c.push(stream, slice(0, 500))
+        assert reply["type"] == "ack" and reply["windows_closed"] > 0
+        _, m = await http_get(server, "/metrics")
+        assert m["windows_counted"] == [reply["windows_closed"]]
+        c.close()
+        await server.stop(checkpoint=False)
+
+    asyncio.run(scenario())
+
+
+PHASES = {"decode", "queue_wait", "wal_append", "windowize", "wal_sync",
+          "submit", "count_wait", "estimator", "collect", "fanout"}
+
+
+def test_phase_timers_in_metrics(tmp_path):
+    """Every phase timer is published with counts that agree with the
+    cycles, dispatches and reaps the run made.  Two tenants' pushes share
+    one coalesced cycle, so the second push's ``engine.push`` reaps the
+    first one's dispatch itself: ``count_wait`` sees that reap,
+    ``reap_wait_ms`` (the server's own reaps) does not."""
+    streams = tenant_streams(2)
+
+    async def scenario():
+        # flush_every 1, as deployed: every push that closes a window
+        # dispatches it from inside engine.push
+        server = await StreamServer(
+            nt_w=NT_W, alpha0=ALPHA0, tenants={"t0": 0, "t1": 1},
+            config=EngineConfig(tier="numpy", flush_every=1), flush_ms=500.0,
+            checkpoint_dir=str(tmp_path / "ckpt")).start()
+        clients = [await Client.connect(server, t) for t in ("t0", "t1")]
+        for k in range(2):
+            sl = slice(500 * k, 500 * (k + 1))
+            replies = await asyncio.gather(
+                *[c.push(st, sl) for c, st in zip(clients, streams)])
+            assert all(r["type"] == "ack" and r["windows_closed"] > 0
+                       for r in replies), replies
+        _, m = await http_get(server, "/metrics")
+        agg = m["aggregate"]
+        phases = agg["phases_ms"]
+        assert set(phases) == PHASES
+        for t in phases.values():
+            assert t["count"] > 0 and t["max"] >= t["mean"] >= 0.0
+            assert t["p99"] >= t["p50"]
+            assert sum(t["buckets"].values()) == t["count"]
+        assert agg["pushes"] == 2 and agg["coalesced_items"] == 4
+        assert phases["wal_sync"]["count"] == agg["pushes"]
+        assert phases["decode"]["count"] == 4
+        assert phases["queue_wait"]["count"] == 4
+        assert phases["wal_append"]["count"] == 4
+        assert phases["windowize"]["count"] == 4
+        # at budget 0 each push that closes windows dispatches them, and
+        # /metrics settles the last dispatch before counting
+        assert agg["dispatch_count"] == 4
+        assert agg["windows_dispatched"] == sum(m["windows_counted"])
+        assert agg["coalesced_windows_per_dispatch"] == \
+            agg["windows_dispatched"] / agg["dispatch_count"]
+        assert phases["count_wait"]["count"] == agg["dispatch_count"]
+        assert phases["estimator"]["count"] == agg["dispatch_count"]
+        # a pack span and one launch span per bucket, per dispatch
+        assert phases["submit"]["count"] > agg["dispatch_count"]
+        assert agg["reap_wait_ms"]["count"] < phases["count_wait"]["count"]
+        assert agg["executor"] == {"wedge_lanes": 0, "wedges": 0}
+        for c in clients:
+            c.close()
+        await server.stop()
+        assert server.metrics.snapshot()["aggregate"]["phases_ms"][
+            "checkpoint"]["count"] == 1
+
+    asyncio.run(scenario())
+
+
+def test_phase_spans_are_flat(monkeypatch):
+    """No ``sgrapp.*`` span opens inside another on the same thread, over
+    a served run with subscribers, a result and a finalize."""
+    from repro.core import telemetry
+
+    depth, opened = {}, []
+
+    class Recorder:
+        def __init__(self, name, **args):
+            self.name = name
+
+        def __enter__(self):
+            key = threading.get_ident()
+            depth[key] = depth.get(key, 0) + 1
+            opened.append((self.name, depth[key]))
+
+        def __exit__(self, *exc):
+            depth[threading.get_ident()] -= 1
+
+    monkeypatch.setattr(telemetry, "TraceAnnotation", Recorder)
+    stream = tenant_streams(1)[0]
+
+    async def scenario():
+        server = await StreamServer(nt_w=NT_W, alpha0=ALPHA0,
+                                    tenants={"t0": 0}, config=CFG).start()
+        c = await Client.connect(server, "t0")
+        await c.call({"type": "subscribe"})
+        for k in range(0, 900, 300):
+            assert (await c.push(stream, slice(k, k + 300)))["type"] == "ack"
+        assert (await c.call({"type": "result"}))["type"] == "result"
+        assert (await c.call({"type": "finalize"}))["type"] == "finalized"
+        c.close()
+        await server.stop(checkpoint=False)
+
+    asyncio.run(scenario())
+    names = {n for n, _ in opened}
+    assert {"sgrapp.decode", "sgrapp.windowize", "sgrapp.pack",
+            "sgrapp.launch", "sgrapp.count_wait", "sgrapp.estimate",
+            "sgrapp.collect", "sgrapp.fanout"} <= names
+    assert all(d == 1 for _, d in opened), [x for x in opened if x[1] > 1]
 
 
 def test_constructor_validation():
