@@ -582,6 +582,70 @@ def count_butterflies_tiled_multiset(adj: jax.Array,
 # sparse tier (wedge sort + segment_sum; never builds the biadjacency)
 # ---------------------------------------------------------------------------
 
+_SCAN_ROW = 128    # one TPU vreg row of lanes: the block of the running scan
+
+
+def _shift_in(x: jax.Array, k: int, fill: jax.Array) -> jax.Array:
+    """``x`` moved ``k`` places up its last axis, ``fill`` shifted in (one
+    pad op: ``k`` in front, ``-k`` cropped off the end)."""
+    return jax.lax.pad(x, fill, [(0, 0, 0)] * (x.ndim - 1) + [(k, -k, 0)])
+
+
+def _running(op, x: jax.Array) -> jax.Array:
+    """Inclusive running ``op`` (``lax.max`` or ``lax.add``) along the last
+    axis, in log depth with elementwise ops only: no gather and no
+    ``while``.  Blocked by rows of ``_SCAN_ROW``: a shift-and-combine
+    doubling inside each row, then the row totals' own running ``op``,
+    shifted to exclusive and combined into every row.
+
+    Neither ``lax.cummax`` / ``lax.cumsum`` (on the TPU they lower to a
+    ``reduce_window`` that takes the compiler tens of seconds at a wedge
+    lane of 2**20 slots) nor ``lax.associative_scan`` (its strided slices
+    and interleaving pads take the TPU compiler over a minute at 2**20).
+    Built from ``lax`` ops alone: every bucket program traces it, warm
+    compile cache or not."""
+    fill = jnp.array(0 if op is jax.lax.add else
+                     -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) else
+                     jnp.iinfo(x.dtype).min, x.dtype)
+    n = x.shape[-1]
+    if n <= _SCAN_ROW:
+        k = 1
+        while k < n:
+            x = op(x, _shift_in(x, k, fill))
+            k *= 2
+        return x
+    rows = -(-n // _SCAN_ROW)
+    lead = x.shape[:-1]
+    y = jax.lax.pad(x, fill,
+                    [(0, 0, 0)] * len(lead) + [(0, rows * _SCAN_ROW - n, 0)])
+    y = _running(op, y.reshape(lead + (rows, _SCAN_ROW)))
+    carry = _shift_in(_running(op, y[..., -1]), 1, fill)
+    y = op(y, jax.lax.broadcast_in_dim(carry, y.shape,
+                                       tuple(range(y.ndim - 1))))
+    return y.reshape(lead + (rows * _SCAN_ROW,))[..., :n]
+
+
+def _slot_owner_lanes(r: jax.Array, base: jax.Array, wedge_cap: int,
+                      *values: jax.Array) -> tuple:
+    """Each wedge slot's owner edge, read as ``values`` (int32) of that edge.
+
+    Edge ``t`` owns the wedge slots ``[base[t], base[t] + r[t])``; the
+    ranges of the edges with ``r > 0`` tile the wedges from slot 0 on.
+    Adding each owner's value at the first slot of its range and subtracting
+    it at the first slot past it, then taking the running sum, leaves the
+    value on every slot of the range and 0 past the last wedge.  Each
+    scatter writes ``2 * cap_e`` lanes and drops those at or past
+    ``wedge_cap``.
+    """
+    owns = r > 0
+    at = jnp.concatenate([jnp.where(owns, base, wedge_cap),
+                          jnp.where(owns, base + r, wedge_cap)])
+    return tuple(
+        _running(jax.lax.add, jnp.zeros(wedge_cap, v.dtype).at[at].add(
+            jnp.concatenate([v, -v]), mode="drop"))
+        for v in values)
+
+
 def count_butterflies_sparse(
     edge_i: jax.Array,
     edge_j: jax.Array,
@@ -601,25 +665,29 @@ def count_butterflies_sparse(
     2. a second sort compacts the surviving edges back into
        contiguous j-groups (dup lanes rejoin the sentinel group);
     3. every edge of in-group rank ``r`` owes ``r`` wedges, one per earlier
-       group member; an inclusive rank cumsum + ``searchsorted`` scatters
-       the wedge slots ``[0, wedge_cap)`` to their ``(earlier, later)``
-       edge pair — in-group ``i`` is ascending and deduped, so the wedge
-       endpoints satisfy ``i1 < i2`` by construction;
+       group member, in the wedge slots from its base ``cumsum(r) - r`` on;
+       each owner's ``i`` and its offset from base to group start are
+       scattered at the ends of its slot range and spread over the range by
+       a running sum (:func:`_slot_owner_lanes`), which yields each slot's
+       ``(earlier, later)`` edge pair — in-group ``i`` is ascending and
+       deduped, so the wedge endpoints satisfy ``i1 < i2`` by construction;
     4. sort wedges by ``(i1, i2)`` and aggregate each run of equal keys:
        summing every live wedge's within-run rank is exactly
        ``sum_runs C(mult, 2)`` — the segment-sum of wedge multiplicities
        with the C(w, 2) epilogue algebraically folded in; each run's start
-       is a ``searchsorted`` of the sorted keys into themselves instead of
-       a segment scatter (scatters are the slowest primitive on every XLA
-       backend).
+       is the running maximum of the run heads' positions.
 
     Both sort phases pack their id pair into a single int32 key — XLA's
     variadic multi-key sort lowers to a generic comparator loop that is
     several times slower than the single-key path.  The sorts are unstable:
     equal int keys are indistinguishable, so the result is the same, and the
-    TPU compiler builds an unstable sort several times faster.  For the same
-    reason the wedge-run starts avoid ``cummax``: over a wedge lane of 2**20
-    slots it alone takes the TPU compiler about 35 s.
+    TPU compiler builds an unstable sort several times faster.  Every
+    running sum and maximum over the wedge lane is a blocked log-depth scan
+    of elementwise ops (:func:`_running`): a binary search
+    (``searchsorted``) makes one gather pass over the whole lane per step,
+    and ``cummax`` takes the TPU compiler tens of seconds at 2**20 slots.
+    The only gather left on the wedge lane reads each wedge's earlier
+    endpoint.
 
     ``wedge_cap`` must bound the window's wedge count (the executor computes
     it host-side per bucket and rounds it up the capacity ladder); dead
@@ -658,22 +726,23 @@ def count_butterflies_sparse(
     r = jnp.where(live, pos - start, 0)
     cum_r = jnp.cumsum(r)                       # inclusive; total wedges last
     total_w = cum_r[-1]
+    base = cum_r - r                            # each edge's first slot
+    # slot w of owner t pairs it with the edge at start[t] + (w - base[t])
+    off_t, i2_t = _slot_owner_lanes(r, base, wedge_cap, start - base, ii)
     w = jnp.arange(wedge_cap, dtype=jnp.int32)
-    t = jnp.clip(jnp.searchsorted(cum_r, w, side="right"), 0, cap_e - 1)
-    t = t.astype(jnp.int32)
-    p = start[t] + (w - (cum_r[t] - r[t]))      # the earlier in-group edge
+    p = jnp.clip(w + off_t, 0, cap_e - 1)       # the earlier in-group edge
     alive = w < total_w
-    i1 = jnp.where(alive, ii[jnp.clip(p, 0, cap_e - 1)], n_i)
-    i2 = jnp.where(alive, ii[t], n_i)
+    i1 = jnp.where(alive, ii[p], n_i)
+    i2 = jnp.where(alive, i2_t, n_i)
     # aggregate wedge multiplicities: sort the packed (i1, i2) keys, then
     # sum each live wedge's rank within its run of equal keys — a run of
     # multiplicity m contributes 0 + 1 + ... + (m-1) = C(m, 2), which is
     # exactly the per-key butterfly count, summed without a segment scatter
     # dead wedges (>= n_i * span) sort last
     wkey = jax.lax.sort(i1 * span_i + i2, is_stable=False)
-    wpos = jnp.arange(wedge_cap, dtype=jnp.int32)
-    wstart = jnp.searchsorted(wkey, wkey, side="left").astype(jnp.int32)
-    wrank = jnp.where(wkey < jnp.int32(n_i) * span_i, wpos - wstart, 0)
+    head = (w == 0) | (wkey != jnp.roll(wkey, 1))
+    wstart = _running(jax.lax.max, jnp.where(head, w, -1))
+    wrank = jnp.where(wkey < jnp.int32(n_i) * span_i, w - wstart, 0)
     return jnp.sum(wrank.astype(acc))
 
 
@@ -692,13 +761,16 @@ def count_butterflies_sparse_multiset(
     packing, so no dup-invalidation resort is needed here).
 
     The schedule mirrors the distinct tier — edge sort by packed ``(j, i)``
-    key (multiplicities ride as the sort payload), rank-cumsum wedge-slot
-    emission — but each wedge carries weight ``mult(i1, j) * mult(i2, j)``
-    and the per-run epilogue becomes ``(S^2 - S2) / 2`` with ``S`` /
-    ``S2`` the run's weight and squared-weight sums, evaluated at run tails
-    from exclusive-cumsum run bases (cummax-propagated, scatter-free —
-    both cumsums are non-decreasing since weights are >= 0).  All static
-    shapes; same int32 key-packing bound as the distinct tier.
+    key (multiplicities ride as the sort payload), wedge-slot owners by
+    scatter and running sum, the owner's multiplicity with them — but each
+    wedge carries weight ``mult(i1, j) * mult(i2, j)`` and the per-run
+    epilogue becomes ``(S^2 - S2) / 2`` with ``S`` / ``S2`` the run's
+    weight and squared-weight sums, evaluated at run tails from exclusive
+    running-sum run bases (carried by a running maximum, scatter-free —
+    both running sums are non-decreasing since weights are >= 0).  Every
+    running sum and maximum over the wedge lane is a blocked log-depth scan
+    of elementwise ops (:func:`_running`).  All static shapes; same int32
+    key-packing bound as the distinct tier.
     """
     if wedge_cap < 1:
         raise ValueError("wedge_cap must be >= 1")
@@ -721,34 +793,34 @@ def count_butterflies_sparse_multiset(
     jj = ekey // span_i
     ii = ekey - jj * span_i
     live = jj < n_j
-    # in-group rank r and wedge-slot emission, exactly as the distinct tier
+    # in-group rank r and wedge-slot owners, exactly as the distinct tier;
+    # the owner's multiplicity rides along
     is_start = first | (jj != jnp.roll(jj, 1))
     start = jax.lax.cummax(jnp.where(is_start, pos, -1))
     r = jnp.where(live, pos - start, 0)
     cum_r = jnp.cumsum(r)
     total_w = cum_r[-1]
+    base = cum_r - r
+    off_t, i2_t, m2_t = _slot_owner_lanes(r, base, wedge_cap,
+                                          start - base, ii, mm)
     w = jnp.arange(wedge_cap, dtype=jnp.int32)
-    t = jnp.clip(jnp.searchsorted(cum_r, w, side="right"), 0, cap_e - 1)
-    t = t.astype(jnp.int32)
-    p = jnp.clip(start[t] + (w - (cum_r[t] - r[t])), 0, cap_e - 1)
+    p = jnp.clip(w + off_t, 0, cap_e - 1)
     alive = w < total_w
     i1 = jnp.where(alive, ii[p], n_i)
-    i2 = jnp.where(alive, ii[t], n_i)
+    i2 = jnp.where(alive, i2_t, n_i)
     macc = mm.astype(acc)
-    ww = jnp.where(alive, macc[p] * macc[t], 0.0)       # wedge weight
+    ww = jnp.where(alive, macc[p] * m2_t.astype(acc), 0.0)  # wedge weight
     # aggregate weighted wedges: sort packed (i1, i2) keys with the weight
     # as payload (dead wedges share the sentinel key and carry weight 0, so
     # their run contributes S = S2 = 0), then the per-run (S^2 - S2)/2
-    # epilogue at run tails — run bases are the exclusive cumsums at run
-    # heads, propagated by cummax (both cumsums are non-decreasing)
+    # epilogue at run tails — run bases are the exclusive running sums at
+    # run heads, carried by a running maximum (both sums are non-decreasing)
     wkey, ww = jax.lax.sort_key_val(i1 * span_i + i2, ww)
-    wpos = jnp.arange(wedge_cap, dtype=jnp.int32)
-    head = (wpos == 0) | (wkey != jnp.roll(wkey, 1))
-    c1 = jnp.cumsum(ww)
-    c2 = jnp.cumsum(ww * ww)
-    base1 = jax.lax.cummax(jnp.where(head, c1 - ww, -1.0))
-    base2 = jax.lax.cummax(jnp.where(head, c2 - ww * ww, -1.0))
-    tail = jnp.roll(head, -1) | (wpos == wedge_cap - 1)
+    head = (w == 0) | (wkey != jnp.roll(wkey, 1))
+    c1, c2 = _running(jax.lax.add, jnp.stack([ww, ww * ww]))
+    base1, base2 = _running(jax.lax.max, jnp.where(
+        head, jnp.stack([c1 - ww, c2 - ww * ww]), -1.0))
+    tail = jnp.roll(head, -1) | (w == wedge_cap - 1)
     s1 = c1 - base1
     s2 = c2 - base2
     return jnp.sum(jnp.where(tail, (s1 * s1 - s2) * 0.5, 0.0))
